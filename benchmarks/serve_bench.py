@@ -62,13 +62,11 @@ def run(
     from repro.serve.query_server import QueryServer
 
     # the cold path measures a genuinely fresh compile per request; a
-    # persistent (on-disk) compilation cache — e.g. the one CI restores for
-    # the test jobs — would serve those compiles from disk and deflate the
-    # warm/cold ratio this bench gates on, so switch it off here
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-    except Exception:
-        pass
+    # persistent (on-disk) compilation cache — the one ``connect`` turns on,
+    # or one CI restores — would serve those compiles from disk and deflate
+    # the warm/cold ratio this bench gates on, so switch it off for this
+    # process
+    jax.config.update("jax_enable_compilation_cache", False)
 
     rng = np.random.default_rng(seed)
     db = tpch.generate(scale=scale, seed=seed).tables()

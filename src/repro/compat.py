@@ -1,68 +1,28 @@
-"""jax version compatibility — single source for API drift.
+"""Mesh and shard_map construction, in one place.
 
-The repo targets the modern jax surface (``jax.shard_map``,
-``jax.sharding.AxisType``, differentiable ``optimization_barrier``); older
-runtimes (0.4.x) spell these differently or lack them.  Every module that
-touches one of these goes through this shim so version logic lives in one
-place.
+Every module that builds a mesh or a ``shard_map`` region goes through these
+two helpers, so the repository's choices for them (Auto axis types; no
+replication check) are made once.
 """
 from __future__ import annotations
 
-import functools
 from typing import Sequence
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]) -> "jax.sharding.Mesh":
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    try:
-        from jax.sharding import AxisType
-
-        return jax.make_mesh(
-            tuple(axis_shapes),
-            tuple(axis_names),
-            axis_types=(AxisType.Auto,) * len(tuple(axis_names)),
-        )
-    except (ImportError, TypeError):
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
-
-
-def shard_map(fn, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` (new) / ``jax.experimental.shard_map`` (old), with
-    replication checking off — dictionary builds start from shard-invariant
-    empties, which the checker cannot see."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    """``jax.make_mesh`` with Auto axis types."""
+    names = tuple(axis_names)
+    return jax.make_mesh(
+        tuple(axis_shapes), names, axis_types=(AxisType.Auto,) * len(names)
     )
 
 
-def axis_size(axis) -> int:
-    """``lax.axis_size`` (new) / ``psum(1, axis)`` (old) for a named mesh
-    axis or axis tuple, inside a shard_map/pmap region."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
-
-
-@functools.lru_cache(maxsize=None)
-def _barrier_differentiable() -> bool:
-    try:
-        jax.grad(lambda x: jax.lax.optimization_barrier(x * 1.0))(1.0)
-        return True
-    except NotImplementedError:
-        return False
-
-
-def optimization_barrier(x):
-    """``lax.optimization_barrier`` where it is differentiable; identity
-    otherwise (the barrier is a perf hint — correctness never depends on it)."""
-    if _barrier_differentiable():
-        return jax.lax.optimization_barrier(x)
-    return x
+def shard_map(fn, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with replication checking off — dictionary builds
+    start from shard-invariant empties, which the checker cannot see."""
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )

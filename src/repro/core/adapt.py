@@ -258,8 +258,12 @@ class AdaptivePlanner:
         candidates: Sequence[str] = DEFAULT_CANDIDATES,
         net=None,
         sharded_rels: Optional[Tuple[str, ...]] = None,
+        clock: Callable[[], float] = time.perf_counter,
     ):
         self.expr = expr
+        #: the timer races measure lanes with — injectable, so a test can
+        #: substitute measurements it controls for wall-clock ones
+        self.clock = clock
         self.sigma = sigma
         self.delta = delta
         self.make_executor = make_executor
@@ -332,9 +336,9 @@ class AdaptivePlanner:
                 continue  # never adopt (or learn from) an unvalidated lane
             best = float("inf")
             for _ in range(max(1, cfg.repeats)):
-                t0 = time.perf_counter()
+                t0 = self.clock()
                 _block(ex(params))
-                best = min(best, time.perf_counter() - t0)
+                best = min(best, self.clock() - t0)
             lane.measured_s = best
             self._recalibrate(cand, best)
         winner = min(

@@ -66,10 +66,11 @@ def lane_identity_row(ops, V: int, dtype=jnp.float32) -> jax.Array:
 
 def combine_at(tv: jax.Array, idx: jax.Array, vs: jax.Array, ops) -> jax.Array:
     """Scatter-combine value rows into ``tv`` at ``idx`` (drop-mode), each
-    lane under its own monoid; all-sum keeps the one-shot ``.add``."""
-    if all_sum(ops):
-        return tv.at[idx].add(vs, mode="drop")
-    for j, op in enumerate(ops):
+    lane under its own monoid (all-sum when ``ops`` is empty).  Lane by lane:
+    each scatter's update is a 1-D ``[n]`` column, where a whole-row update
+    would be a row-major ``[n, V]`` array that a TPU tiles (8, 128), padding
+    V lanes to 128 in HBM.  Each element still lands in the same order."""
+    for j, op in enumerate(ops or ("sum",) * vs.shape[1]):
         col = vs[:, j]
         if op == "sum":
             tv = tv.at[idx, j].add(col, mode="drop")
@@ -380,10 +381,16 @@ def blocked_lookup(
     blk = jnp.searchsorted(table.block_max, qs, side="left")
     blk = jnp.minimum(blk, nb - 1)
     base = blk * block
-    # within-block: gather the block row per query and count keys < q
-    offs = jnp.arange(block, dtype=jnp.int32)
-    rows = table.keys[base[:, None] + offs[None, :]]  # [n, block]
-    lt = jnp.sum((rows < qs[:, None]).astype(jnp.int32), axis=1)
+    # within-block: branchless binary search for the count of keys < q,
+    # log2(block) rounds of one [n] gather — an [n, block] gather of whole
+    # block rows would hold n·block keys at once.  The count saturates at
+    # block-1; a query above every key of its block misses either way.
+    lt = jnp.zeros_like(qs)
+    bit = block >> 1
+    while bit:
+        cand = lt + bit
+        lt = jnp.where(table.keys[base + cand - 1] < qs, cand, lt)
+        bit >>= 1
     idx = jnp.minimum(base + lt, table.keys.shape[0] - 1)
     found = table.keys[idx] == qs
     vals = jnp.where(found[:, None], table.vals[idx], 0.0)

@@ -39,6 +39,7 @@ from typing import Dict, Tuple, Union
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro import compat
@@ -51,10 +52,10 @@ Axis = Union[str, Tuple[str, ...]]
 
 def _axis_size(axis: Axis) -> jax.Array:
     if isinstance(axis, str):
-        return compat.axis_size(axis)
+        return lax.axis_size(axis)
     n = 1
     for a in axis:
-        n = n * compat.axis_size(a)
+        n = n * lax.axis_size(a)
     return n
 
 
@@ -244,6 +245,37 @@ def _plan_exchange(node, built, *, axis: Axis):
     return E.BuiltDict(res, built.choice, lanes=built.lanes, kind=built.kind)
 
 
+def _place_inputs(db, mesh, axis: Axis, shard_rels, n_sh: int):
+    """Place every relation's columns and mask on the mesh once, at executor
+    build: relations in ``shard_rels`` are padded to a multiple of ``n_sh``
+    rows and split along ``axis``, the rest are replicated.  Calls then feed
+    arrays that already sit where ``shard_map`` wants them, so no launch
+    moves a fact table off the device it was loaded on.  Returns
+    ``(cols, masks, col_specs, mask_specs, sorted_on)`` keyed by relation."""
+    cols_in, masks_in, col_specs, mask_specs, sorted_meta = {}, {}, {}, {}, {}
+    for rel, t in db.items():
+        mask = t.live_mask()
+        cols = dict(t.columns)
+        if rel in shard_rels:
+            pad = (-t.nrows) % n_sh
+            if pad:
+                cols = {
+                    c: jnp.concatenate([v, jnp.zeros((pad,), v.dtype)])
+                    for c, v in cols.items()
+                }
+                mask = jnp.concatenate([mask, jnp.zeros((pad,), bool)])
+            spec = P(axis)
+        else:
+            spec = P()
+        placed = NamedSharding(mesh, spec)
+        cols_in[rel] = {c: jax.device_put(v, placed) for c, v in cols.items()}
+        masks_in[rel] = jax.device_put(mask, placed)
+        col_specs[rel] = {c: spec for c in cols}
+        mask_specs[rel] = spec
+        sorted_meta[rel] = t.sorted_on
+    return cols_in, masks_in, col_specs, mask_specs, sorted_meta
+
+
 def sharded_executor(
     plan,
     db,
@@ -298,26 +330,9 @@ def sharded_executor(
     for a in axes:
         n_sh *= mesh.shape[a]
 
-    cols_in, masks_in, col_specs, mask_specs, sorted_meta = {}, {}, {}, {}, {}
-    for rel, t in db.items():
-        mask = t.live_mask()
-        cols = dict(t.columns)
-        if rel in shard_rels:
-            pad = (-t.nrows) % n_sh
-            if pad:
-                cols = {
-                    c: jnp.concatenate([v, jnp.zeros((pad,), v.dtype)])
-                    for c, v in cols.items()
-                }
-                mask = jnp.concatenate([mask, jnp.zeros((pad,), bool)])
-            spec = PSpec(axis)
-        else:
-            spec = PSpec()
-        cols_in[rel] = cols
-        masks_in[rel] = mask
-        col_specs[rel] = {c: spec for c in cols}
-        mask_specs[rel] = spec
-        sorted_meta[rel] = t.sorted_on
+    cols_in, masks_in, col_specs, mask_specs, sorted_meta = _place_inputs(
+        db, mesh, axis, shard_rels, n_sh
+    )
 
     # parameter values are replicated scalars; stable dtypes keep the trace
     param_specs = {name: PSpec() for name in plan.param_names()}
@@ -397,6 +412,7 @@ def sharded_executor(
             return out
 
         run_scalar.trace_counter = trace_counter
+        run_scalar.inputs = (cols_in, masks_in)
         run_scalar.last_report = None
         run_scalar.fused_regions = fused_regions
         run_scalar.n_shards = n_sh
@@ -439,6 +455,7 @@ def sharded_executor(
         )
 
     run.trace_counter = trace_counter
+    run.inputs = (cols_in, masks_in)
     run.last_report = None
     run.fused_regions = fused_regions
     run.n_shards = n_sh
@@ -487,26 +504,9 @@ def sharded_shared_executor(
     for a in axes:
         n_sh *= mesh.shape[a]
 
-    cols_in, masks_in, col_specs, mask_specs, sorted_meta = {}, {}, {}, {}, {}
-    for rel, t in db.items():
-        mask = t.live_mask()
-        cols = dict(t.columns)
-        if rel in shard_rels:
-            pad = (-t.nrows) % n_sh
-            if pad:
-                cols = {
-                    c: jnp.concatenate([v, jnp.zeros((pad,), v.dtype)])
-                    for c, v in cols.items()
-                }
-                mask = jnp.concatenate([mask, jnp.zeros((pad,), bool)])
-            spec = PSpec(axis)
-        else:
-            spec = PSpec()
-        cols_in[rel] = cols
-        masks_in[rel] = mask
-        col_specs[rel] = {c: spec for c in cols}
-        mask_specs[rel] = spec
-        sorted_meta[rel] = t.sorted_on
+    cols_in, masks_in, col_specs, mask_specs, sorted_meta = _place_inputs(
+        db, mesh, axis, shard_rels, n_sh
+    )
 
     param_specs = tuple(
         {name: PSpec() for name in p.param_names()} for p in plans
@@ -659,6 +659,11 @@ class ShardedExecutable:
         return self._run.trace_counter[0]
 
     @property
+    def inputs(self):
+        """``(cols, masks)`` as placed on the mesh at build, by relation."""
+        return self._run.inputs
+
+    @property
     def last_report(self):
         return getattr(self._run, "last_report", None)
 
@@ -738,6 +743,7 @@ def cached_sharded_executor(
         return run({**bound, **(params or {})})
 
     bound_run.trace_counter = run.trace_counter
+    bound_run.inputs = run.inputs
     bound_run.fused_regions = run.fused_regions
     bound_run.n_shards = run.n_shards
     return bound_run

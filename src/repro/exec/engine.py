@@ -1737,21 +1737,15 @@ def _stream_kernel_chunks(
     term = rest[-1]
     nchunks = ct.n_chunks
     t_kern = time.perf_counter()
-    try:
-        t0 = ct.chunk_device(0, needed, pad=True)
-        f0 = Frame({var: t0}, (var,), {var: rel})
-        scratch_env, scratch_refs = dict(env), {}
-        ok = bool(
-            _kernel_pipeline(
-                pipe, rest, f0, scratch_env, scratch_refs, sigma,
-                allow_sorted, params, seg.need,
-            )
-        )
-    except _errors.ReproError:
-        raise  # injected/typed failure, not a kernel decline
-    except Exception:
-        ok = False
-    if not ok:
+    t0 = ct.chunk_device(0, needed, pad=True)
+    f0 = Frame({var: t0}, (var,), {var: rel})
+    scratch_env, scratch_refs = dict(env), {}
+    # a structural decline is ``_kernel_pipeline`` returning False; anything
+    # it raises (a kernel that does not lower, an injected fault) propagates
+    if not _kernel_pipeline(
+        pipe, rest, f0, scratch_env, scratch_refs, sigma,
+        allow_sorted, params, seg.need,
+    ):
         return False
     up_next = ct.upload_chunk(1, needed) if nchunks > 1 else None
     state = _merge_dict_tables(
@@ -2199,13 +2193,14 @@ def _kernel_pipeline(pipe, rest, f, env, refs, sigma, allow_sorted, params, need
     # scan variable)
     radix_plan = None
     if radix_sym:
+        from repro.core.lower import _Unsupported
         from repro.core.lower import compile_rowfn_frame as _rf
 
         b = env[radix_sym]
         mod = registry.get(b.res.ds)
         try:
             kvals = jnp.asarray(_rf(radix_key, f.tables, params), jnp.int32)
-        except Exception:
+        except _Unsupported:
             return False  # key not computable from the stream: XLA path
         part = mod.partition_assign(b.res.table, kvals, n_parts)
         cols, live, radix_plan = _fp.radix_route(

@@ -131,6 +131,30 @@ class Query:
 
 
 # ---------------------------------------------------------------------------
+# reference helpers: numpy, independent of the engine
+# ---------------------------------------------------------------------------
+
+
+def _group_sum(keys: np.ndarray, weights: np.ndarray):
+    """(distinct keys ascending, float64 sum of ``weights`` per key).  Each
+    key's sum accumulates in row order, as a row-at-a-time loop adding
+    ``float(w)`` would, so the result is the same to the last bit."""
+    uniq, inv = np.unique(keys, return_inverse=True)
+    sums = np.bincount(inv, weights=weights.astype(np.float64), minlength=len(uniq))
+    return uniq, sums
+
+
+def _row_of(table_keys: np.ndarray, probes: np.ndarray):
+    """(row index of each probe in ``table_keys``, found mask); missing
+    probes get row 0 and found False."""
+    order = np.argsort(table_keys, kind="stable")
+    sk = table_keys[order]
+    at = np.minimum(np.searchsorted(sk, probes), max(len(sk) - 1, 0))
+    found = (sk[at] == probes) if len(sk) else np.zeros(len(probes), bool)
+    return order[at] if len(sk) else at, found
+
+
+# ---------------------------------------------------------------------------
 # Q1 — scan-heavy multi-aggregate group-by on lineitem (tiny group count)
 # ---------------------------------------------------------------------------
 
@@ -212,14 +236,11 @@ def q3_run(db, choices, **params):
 def q3_reference(db, date: float = 0.05):
     li, od = db["lineitem"], db["orders"]
     sel = np.asarray(od.col("orderdate")) < date
-    ok = set(np.asarray(od.col("orderkey"))[sel].tolist())
     k = np.asarray(li.col("orderkey"))
     v = np.asarray(li.col("extendedprice")) * (1 - np.asarray(li.col("discount")))
-    out = {}
-    for kk, vv in zip(k, v):
-        if int(kk) in ok:
-            out[int(kk)] = out.get(int(kk), 0.0) + float(vv)
-    return {k2: np.array([v2], np.float32) for k2, v2 in out.items()}
+    hit = np.isin(k, np.asarray(od.col("orderkey"))[sel])
+    keys, sums = _group_sum(k[hit], v[hit])
+    return {int(kk): np.array([vv], np.float32) for kk, vv in zip(keys, sums)}
 
 
 # ---------------------------------------------------------------------------
@@ -340,23 +361,17 @@ def q5_reference(db, region: int = 0):
     )
     reg = np.asarray(na.col("regionkey"))
     cn = np.asarray(cu.col("nationkey"))
-    cust_ok = reg[cn] == region
-    ord_nat = {}
-    ok_arr = np.asarray(od.col("orderkey"))
     ock = np.asarray(od.col("custkey"))
-    for okey, ck in zip(ok_arr, ock):
-        if cust_ok[ck]:
-            ord_nat[int(okey)] = int(cn[ck])
+    # each lineitem's order row, then that order's customer nation (-1 when
+    # the order is missing or its customer is outside the region)
+    pos, found = _row_of(np.asarray(od.col("orderkey")), np.asarray(li.col("orderkey")))
+    ck = ock[pos]
+    nat = np.where(found & (reg[cn[ck]] == region), cn[ck], -1)
     sn = np.asarray(su.col("nationkey"))
-    out = {}
-    lk = np.asarray(li.col("orderkey"))
-    ls = np.asarray(li.col("suppkey"))
     rv = np.asarray(li.col("extendedprice")) * (1 - np.asarray(li.col("discount")))
-    for okey, sk, r in zip(lk, ls, rv):
-        nat = ord_nat.get(int(okey))
-        if nat is not None and sn[sk] == nat:
-            out[nat] = out.get(nat, 0.0) + float(r)
-    return {k: np.array([v], np.float32) for k, v in out.items()}
+    keep = (nat >= 0) & (sn[np.asarray(li.col("suppkey"))] == nat)
+    keys, sums = _group_sum(nat[keep], rv[keep])
+    return {int(kk): np.array([vv], np.float32) for kk, vv in zip(keys, sums)}
 
 
 # ---------------------------------------------------------------------------
@@ -462,21 +477,19 @@ def q9_reference(db, color: int = 3):
     pprice = np.asarray(pa.col("retailprice"))
     sn = np.asarray(su.col("nationkey"))
     odate = np.asarray(od.col("orderdate"))
-    out = {}
     lk = np.asarray(li.col("partkey"))
-    lsk = np.asarray(li.col("suppkey"))
-    lok = np.asarray(li.col("orderkey"))
-    ep = np.asarray(li.col("extendedprice"))
-    dc = np.asarray(li.col("discount"))
-    qt = np.asarray(li.col("quantity"))
-    for i in range(len(lk)):
-        if pcol[lk[i]] != color:
-            continue
-        year = int(odate[lok[i]] * _YEARS)
-        key = int(sn[lsk[i]]) * _YEARS + year
-        profit = ep[i] * (1 - dc[i]) - qt[i] * pprice[lk[i]] * 0.01
-        out[key] = out.get(key, 0.0) + float(profit)
-    return {k: np.array([v], np.float32) for k, v in out.items()}
+    hit = pcol[lk] == color
+    lk = lk[hit]
+    lsk = np.asarray(li.col("suppkey"))[hit]
+    lok = np.asarray(li.col("orderkey"))[hit]
+    ep = np.asarray(li.col("extendedprice"))[hit]
+    dc = np.asarray(li.col("discount"))[hit]
+    qt = np.asarray(li.col("quantity"))[hit]
+    year = (odate[lok] * _YEARS).astype(np.int64)
+    key = sn[lsk].astype(np.int64) * _YEARS + year
+    profit = ep * (1 - dc) - qt * pprice[lk] * 0.01
+    keys, sums = _group_sum(key, profit)
+    return {int(kk): np.array([vv], np.float32) for kk, vv in zip(keys, sums)}
 
 
 # ---------------------------------------------------------------------------
@@ -528,16 +541,14 @@ def q18_run(db, choices, **params):
 
 def q18_reference(db, threshold: float = 150.0):
     li, od = db["lineitem"], db["orders"]
-    k = np.asarray(li.col("orderkey"))
-    q = np.asarray(li.col("quantity"))
     tp = np.asarray(od.col("totalprice"))
-    agg = {}
-    for kk, qq in zip(k, q):
-        agg[int(kk)] = agg.get(int(kk), 0.0) + float(qq)
+    keys, sums = _group_sum(
+        np.asarray(li.col("orderkey")), np.asarray(li.col("quantity"))
+    )
+    big = sums > threshold
     return {
-        kk: np.array([vv, tp[kk]], np.float32)
-        for kk, vv in agg.items()
-        if vv > threshold
+        int(kk): np.array([vv, tp[kk]], np.float32)
+        for kk, vv in zip(keys[big], sums[big])
     }
 
 

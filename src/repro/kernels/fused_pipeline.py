@@ -483,7 +483,7 @@ def fused_pipeline(
     col_meta += (("__live__", live_p.dtype, block, None),)
     streams.append(live_p)
     stream_specs = [
-        pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY) for _ in streams
+        pl.BlockSpec(memory_space=pl.ANY) for _ in streams
     ]
 
     dict_syms = tuple(sorted(dicts))

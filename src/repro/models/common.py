@@ -17,7 +17,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.kernels import ops as kops
 
 Params = Dict[str, Any]
@@ -35,7 +34,7 @@ def cast_tree(tree: Params, dtype) -> Params:
     casted = jax.tree.map(
         lambda a: a.astype(dtype) if a.dtype == jnp.float32 else a, tree
     )
-    return compat.optimization_barrier(casted)
+    return jax.lax.optimization_barrier(casted)
 
 
 def dense_init(key, d_in: int, d_out: int, scale: Optional[float] = None):

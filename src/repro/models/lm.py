@@ -21,7 +21,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.sharding.partition import current_mesh, shard_hint
 from . import common, moe as moe_mod
 from .common import Params
@@ -140,7 +139,7 @@ def forward(
         y, aux = (ckpt(period_body) if remat else period_body)(lps, carry)
         # keep the saved carry in the activation dtype — barrier stops XLA
         # from hoisting an f32 convert of the whole residual stack
-        y = compat.optimization_barrier(y)
+        y = jax.lax.optimization_barrier(y)
         return y, aux
 
     assert cfg.n_layers % period == 0, (cfg.n_layers, period)

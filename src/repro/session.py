@@ -27,6 +27,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from dataclasses import dataclass, field
+from pathlib import Path
 from typing import Dict, Optional, Tuple, Union
 
 import numpy as np
@@ -139,8 +140,9 @@ class Session:
 
             if jax.device_count() < self.shards:
                 raise ValueError(
-                    f"need {self.shards} devices, have {jax.device_count()}; "
-                    "set XLA_FLAGS=--xla_force_host_platform_device_count=N"
+                    f"need {self.shards} devices, have {jax.device_count()} "
+                    "(a CPU rehearsal gets N virtual devices from "
+                    "XLA_FLAGS=--xla_force_host_platform_device_count=N)"
                 )
             self.mesh = compat.make_mesh((self.shards,), (self.axis,))
             self.shard_rels = FACT_RELS
@@ -150,9 +152,10 @@ class Session:
         self._last_report: Optional[E.ExecutionReport] = None
 
         # -- fault tolerance (DESIGN.md §12) --------------------------------
-        #: monotonic clock driving circuit-breaker cooldowns — injectable
-        #: (``clock=``) so cooldown tests advance time instead of sleeping
-        self._clock = clock if clock is not None else time.monotonic
+        #: monotonic clock driving circuit-breaker cooldowns and adaptive
+        #: races' lane timings — injectable (``clock=``) so tests advance
+        #: time instead of sleeping, and control what a race measures
+        self._clock = clock if clock is not None else time.perf_counter
         #: consecutive transient failures before a mode counts as broken
         self.breaker_threshold = 2
         #: seconds a tripped (shape, mode) breaker stays open
@@ -455,6 +458,7 @@ class Session:
                 fingerprint=fp,
                 net=self.net,
                 sharded_rels=self.shard_rels or None,
+                clock=self._clock,
             )
             choices = planner.choose(query.bind_defaults({}))
             synth_runs = len(planner.races)  # one enumerate per race round
@@ -562,6 +566,27 @@ class _ParamRunner:
         return self.session._call(self._ex, params)
 
 
+#: the compile cache's home when ``JAX_COMPILATION_CACHE_DIR`` is unset: a
+#: fixed directory inside the checkout (gitignored), so every process of this
+#: checkout finds the executables an earlier one compiled
+COMPILE_CACHE_DIR = str(Path(__file__).resolve().parents[2] / ".jax_cache")
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+    A directory already configured — ``JAX_COMPILATION_CACHE_DIR``, which
+    JAX reads itself, or an earlier ``jax.config`` setting — is kept;
+    otherwise the cache goes to :data:`COMPILE_CACHE_DIR`.  Takes effect
+    for compiles after the first call in a process."""
+    import jax
+
+    configured = jax.config.jax_compilation_cache_dir
+    if configured:
+        return configured
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
+
+
 def connect(
     db,
     memory_budget: Optional[int] = None,
@@ -581,7 +606,10 @@ def connect(
       row-sharded (choices synthesized under Δ_net);
     * ``adapt`` — ``True`` or an :class:`AdaptConfig`: race near-cost plans
       on warm-up traffic, validate bitwise, serve the measured winner.
+
+    Turns on the persistent compilation cache (:func:`use_compile_cache`).
     """
+    use_compile_cache()
     return Session(
         db,
         memory_budget=memory_budget,

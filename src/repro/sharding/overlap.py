@@ -17,14 +17,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 
 
 def ring_allgather_matmul(x_local: jax.Array, w: jax.Array, axis: str) -> jax.Array:
     """x_local: this shard's [m_loc, K] rows of a row-sharded X; w: [K, N]
     local weight.  Returns all_gather(X) @ w = [m_loc * n_shards, N], with
     the gather pipelined against the matmuls."""
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     m_loc = x_local.shape[0]
     out = jnp.zeros((n * m_loc, w.shape[1]), w.dtype)

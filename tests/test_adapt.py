@@ -142,15 +142,34 @@ def test_session_query_params_and_report(db):
 # ---------------------------------------------------------------------------
 
 
-def test_poisoned_model_converges_to_fast_plan(db):
+def test_poisoned_model_converges_to_fast_plan(db, monkeypatch):
     """Price hash ops ~100x under the calibrated truth (the real direction
     of the prior's misprice, exaggerated): Alg. 1 then picks ht_*
     everywhere.  The race measures the st_* swaps faster, installs one as
     the winner immediately, and the residual corrections inflate the
     poisoned coefficients until the MODEL itself re-ranks within the
-    warm-up rounds."""
+    warm-up rounds.
+
+    The race's measurements come from the session's injected clock: each
+    lane run advances it by 1 ms per sort dictionary and 10 ms per hash
+    dictionary, so which lane is fast does not depend on the machine's
+    load (wall-clock races of these sub-millisecond lanes are a coin
+    toss on a busy CPU)."""
     from repro.core.cost import PRIOR_OP_NS
     from repro.core.synthesis import synthesize
+    from repro import session as S
+
+    now = [0.0]
+    run_lane = S._ParamRunner.__call__
+
+    def timed_lane(self, params=None):
+        now[0] += sum(
+            1e-2 if c.ds.startswith("ht") else 1e-3
+            for c in self.choices.values()
+        )
+        return run_lane(self, params)
+
+    monkeypatch.setattr(S._ParamRunner, "__call__", timed_lane)
 
     poisoned_table = dict(PRIOR_OP_NS)
     for key in poisoned_table:
@@ -169,6 +188,7 @@ def test_poisoned_model_converges_to_fast_plan(db):
             band=1e6, top_k=6, warmup=4, repeats=2, residual_alpha=1.0
         ),
         delta=delta,
+        clock=lambda: now[0],
     )
     N = 5
     for _ in range(N):

@@ -173,3 +173,33 @@ def test_ring_allgather_matmul_overlap():
         """
     )
     assert "RING_OK" in out
+
+
+def test_sharded_inputs_placed_on_mesh():
+    """A sharded session's executor places its inputs once, at build: the
+    fact relations split along the mesh axis (each of 4 devices holds a
+    quarter of lineitem), dimensions replicated on every device."""
+    out = _run(
+        """
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.data import tpch
+        from repro.session import connect
+
+        mesh_devices = jax.devices()[:4]
+        db = tpch.generate(scale=0.002, seed=3).tables()
+        sess = connect(dict(db), shards=4)
+        cols, masks = sess.shape("q3").executable.inputs
+        for rel, rc in cols.items():
+            want = P("data") if rel in sess.shard_rels else P()
+            for a in list(rc.values()) + [masks[rel]]:
+                assert isinstance(a.sharding, NamedSharding), (rel, a.sharding)
+                assert a.sharding.spec == want, (rel, a.sharding.spec)
+                assert set(a.sharding.device_set) == set(mesh_devices), rel
+        li = cols["lineitem"]["orderkey"]
+        per_dev = sorted(s.data.shape[0] for s in li.addressable_shards)
+        assert per_dev == [li.shape[0] // 4] * 4, per_dev
+        print("OK")
+        """
+    )
+    assert "OK" in out

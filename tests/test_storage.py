@@ -182,6 +182,25 @@ def test_streamed_executable_dispatch(tpch_pair):
         np.testing.assert_allclose(got[k], ref[k], rtol=1e-5, atol=1e-5)
 
 
+def test_streamed_kernel_failure_propagates(tpch_pair, monkeypatch):
+    """A kernel that fails inside a streamed region (here: the TPU
+    compiler's refusal of a resident probe) reaches the caller; it is not
+    turned into a decline that silently runs the XLA streamed loop."""
+    db, cdb, sigma = tpch_pair
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+
+    def refuse(*args, **kwargs):
+        raise NotImplementedError("Only 2D gather is supported")
+
+    monkeypatch.setattr(E, "_kernel_pipeline", refuse)
+    q = QUERIES["q1"]
+    choices = synthesize(q.llql(), sigma, DELTA).choices
+    plan = P.fuse(compile_plan(q.llql(), choices), sigma=sigma)
+    params = E.coerce_bindings(plan, q.bind_defaults({}))
+    with pytest.raises(NotImplementedError, match="2D gather"):
+        E.execute_plan(plan, cdb, sigma=sigma, params=params)
+
+
 # ---------------------------------------------------------------------------
 # fused kernel: in-register encoded decode, carried accumulator state
 # ---------------------------------------------------------------------------

@@ -90,3 +90,41 @@ def test_train_e2e_loss_decreases(tmp_path):
     t.init()
     log = t.run()
     assert log[-1]["loss"] < log[0]["loss"]
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_location(tmp_path, from_env):
+    """``repro.connect`` turns on jax's persistent compile cache: in
+    ``$JAX_COMPILATION_CACHE_DIR`` when set (and no other directory), else
+    in one fixed directory at the checkout root that git ignores."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.path.join(root, "src"))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    code = (
+        "import jax, repro\n"
+        "from repro.data import tpch\n"
+        "from repro.session import COMPILE_CACHE_DIR\n"
+        "repro.connect(tpch.generate(scale=0.001, seed=0).tables())\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+        "print(COMPILE_CACHE_DIR)\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True, env=env,
+        timeout=300,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    used, fixed = out.stdout.split()[-2:]
+    if from_env:
+        assert used == str(tmp_path)
+    else:
+        assert used == fixed
+        assert os.path.dirname(fixed) == os.path.realpath(root)
+        with open(os.path.join(root, ".gitignore")) as f:
+            ignored = {line.strip().strip("/") for line in f}
+        assert os.path.basename(fixed) in ignored, f"{fixed} is not gitignored"
