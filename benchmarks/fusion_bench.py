@@ -12,8 +12,8 @@ choices and times the SAME plan two ways:
   CPU/XLA, the ``fused_pipeline`` Pallas kernel on TPU) with in-register
   masks and pruned gathers.
 
-Every region's **executed path** is recorded (``engine.REGION_MODES``:
-``kernel-resident`` / ``kernel-radix`` / ``xla`` / ``xla-radix-planned``),
+Every region's **executed path** is recorded (``engine.last_report()``
+region modes: ``kernel-resident`` / ``kernel-radix`` / ``xla`` / ``xla-radix-planned``),
 so speedup numbers are attributable to the path that produced them instead
 of being one opaque ratio.
 

@@ -14,7 +14,8 @@ The five TPC-H queries run twice over the same generated data:
 
 Timed warm, interleaved best-of-N (drift hits both alike).  Device memory
 for the streamed side is the engine's deterministic byte ledger
-(``engine.STREAM_STATS``): 2× the decoded chunk working set (double
+(``ExecutionReport.peak_chunk_bytes`` / ``peak_state_bytes`` of
+``engine.last_report()``): 2× the decoded chunk working set (double
 buffer) + the carried accumulator state — the CPU backend reports no
 allocator stats, so the accounting is arithmetic, not sampled.
 

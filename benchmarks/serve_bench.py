@@ -92,7 +92,8 @@ def run(
         by_query.setdefault(r.qname, []).append(r)
     for qname, rs in sorted(by_query.items()):
         shape = stats["shapes"][qname]
-        # the server was warmed up, so busy_s is pure warm execution wall
+        # the server was warmed up, so busy_s is warm batch wall, from
+        # dispatch to the answers on the host
         sec = shape["busy_s"] / max(1, shape["served"])
         results[f"serve/{qname}/warm"] = {
             "seconds": sec,

@@ -197,8 +197,8 @@ def bitwise_equal(a: Dict[int, np.ndarray], b: Dict[int, np.ndarray]) -> bool:
     return True
 
 
-def _block(out) -> None:
-    """Force completion of an executor result for timing purposes."""
+def block_result(out) -> None:
+    """Wait until an executor result is computed on its device."""
     import jax
 
     if hasattr(out, "arrays"):
@@ -337,7 +337,7 @@ class AdaptivePlanner:
             best = float("inf")
             for _ in range(max(1, cfg.repeats)):
                 t0 = self.clock()
-                _block(ex(params))
+                block_result(ex(params))
                 best = min(best, self.clock() - t0)
             lane.measured_s = best
             self._recalibrate(cand, best)

@@ -123,12 +123,13 @@ def build_dict(
     # build runs inside a jitted region — models cold-path build failures)
     _faults.check("dict-build", detail=ds)
     ops = None if dbase.all_sum(ops) else tuple(ops)
-    if valid is not None:
-        # masked rows become PAD holes; the sorted fast path survives the
-        # mask (dicts.base.build_sorted dedupes sorted-with-holes exactly)
-        t = _jit_build(ds, capacity, assume_sorted, True, ops)(keys, vals, valid)
-    else:
-        t = _jit_build(ds, capacity, assume_sorted, False, ops)(keys, vals)
+    with jax.named_scope(f"{SCOPE_BUILD}.{ds}"):
+        if valid is not None:
+            # masked rows become PAD holes; the sorted fast path survives the
+            # mask (dicts.base.build_sorted dedupes sorted-with-holes exactly)
+            t = _jit_build(ds, capacity, assume_sorted, True, ops)(keys, vals, valid)
+        else:
+            t = _jit_build(ds, capacity, assume_sorted, False, ops)(keys, vals)
     return DictResult(ds, t)
 
 
@@ -140,15 +141,16 @@ def lookup_dict(
 ) -> Tuple[jax.Array, jax.Array]:
     """(vals[n, V], found[n]).  ``sorted_probes`` routes sort-family lookups
     through the merge path (the paper's hinted lookup)."""
-    if d.ds.startswith("st") and sorted_probes:
-        vals, found = kops.merge_lookup(d.table.keys, d.table.vals, queries)
+    with jax.named_scope(f"{SCOPE_PROBE}.{d.ds}"):
+        if d.ds.startswith("st") and sorted_probes:
+            vals, found = kops.merge_lookup(d.table.keys, d.table.vals, queries)
+            if valid is not None:
+                found = found & valid.astype(bool)
+                vals = jnp.where(found[:, None], vals, 0.0)
+            return vals, found
         if valid is not None:
-            found = found & valid.astype(bool)
-            vals = jnp.where(found[:, None], vals, 0.0)
-        return vals, found
-    if valid is not None:
-        return _jit_lookup(d.ds, True)(d.table, queries, valid)
-    return _jit_lookup(d.ds, False)(d.table, queries)
+            return _jit_lookup(d.ds, True)(d.table, queries, valid)
+        return _jit_lookup(d.ds, False)(d.table, queries)
 
 
 # ---------------------------------------------------------------------------
@@ -394,15 +396,10 @@ def execute_plan(
     t_plan = time.perf_counter()
     try:
         for node in plan.nodes:
-            t_node = time.perf_counter()
             _exec_node(
                 node, env, refs, db, sigma, allow_sorted, params,
                 exchange_impl, repartition_impl,
             )
-            if isinstance(node, P.Pipeline):
-                rec = rep.regions.get(node.out)
-                if rec is not None and rec.wall_s == 0.0:
-                    rec.wall_s = time.perf_counter() - t_node
 
         if plan.result is not None and isinstance(
             env.get(plan.result), _PendingStream
@@ -674,34 +671,21 @@ def _exec_node(
 
 
 # ---------------------------------------------------------------------------
-# out-of-core streaming (DESIGN.md §10)
-# ---------------------------------------------------------------------------
-
-# DEPRECATED per-process streaming ledger, reset by ``reset_stream_stats``.
-# Kept populated for external callers; in-repo readers use the structured
-# ``ExecutionReport`` (``last_report()``) instead.  All fields are
-# deterministic byte arithmetic (JAX CPU exposes no allocator high-water
-# mark): ``h2d_bytes`` counts the encoded payload bytes that actually crossed
-# the host→device link, ``peak_chunk_bytes`` the largest decoded working set
-# a streamed region held on device at once (two chunks in flight — compute +
-# prefetch — plus in-transit encoded payloads), ``peak_state_bytes`` the
-# largest carried accumulator state.
-STREAM_STATS: Dict[str, int] = {}
-
-
-def reset_stream_stats() -> None:
-    STREAM_STATS.update(
-        regions=0, chunks=0, h2d_bytes=0, peak_chunk_bytes=0,
-        peak_state_bytes=0,
-    )
-
-
-reset_stream_stats()
-
-
-# ---------------------------------------------------------------------------
 # structured execution telemetry (DESIGN.md §11)
 # ---------------------------------------------------------------------------
+
+#: Names under which the served path shows in a ``jax.profiler`` trace; the
+#: benchmark's per-layer metrics and idle-gap labels read them from here.
+#: Host spans (``jax.profiler.TraceAnnotation``), on the thread that calls
+#: ``QueryServer.step()``:
+SPAN_DISPATCH = "serve.dispatch"  # enqueue a batch's device work
+SPAN_SYNC = "serve.sync"  # wait until the batch's outputs are ready
+SPAN_CONVERT = "serve.convert"  # device-to-host copy and Python result dicts
+#: Device scopes (``jax.named_scope``).  They reach only the HLO ops'
+#: ``op_name`` metadata (a TPU trace's ``tf_op`` stat), never the code.
+SCOPE_BUILD = "dict.build"  # ``dict.build.<ds>``: the body of build_dict
+SCOPE_PROBE = "dict.probe"  # ``dict.probe.<ds>``: the body of lookup_dict
+SCOPE_DECODE = "stream.decode"  # in-trace decode of a streamed chunk
 
 
 @dataclass
@@ -712,15 +696,12 @@ class RegionRecord:
     ("xla", "xla-radix-planned", "kernel-resident", "kernel-radix",
     "streamed:N", "streamed-chained:N", "streamed-kernel:N",
     "streamed-deferred", "shared:N"); ``family`` is the terminal
-    dictionary's ds annotation when the terminal builds one.  ``wall_s``
-    comes from timed dispatch: real elapsed time for the eager streamed
-    paths, trace-time dispatch for jitted resident regions (the end-to-end
-    call wall lives on the report)."""
+    dictionary's ds annotation when the terminal builds one.  The call's
+    wall time lives on the report."""
 
     sym: str
     mode: str = ""
     family: str = ""
-    wall_s: float = 0.0
     chunks: int = 0
     h2d_bytes: int = 0
 
@@ -730,13 +711,16 @@ class ExecutionReport:
     """Structured per-execution telemetry, attached to every
     ``execute_plan`` / ``execute_shared_plan`` / sharded call.
 
-    Replaces the ``REGION_MODES`` / ``STREAM_STATS`` string-dict globals
-    (still maintained as deprecated views): ``regions`` maps each fused
-    region's terminal symbol to its :class:`RegionRecord`; the scalar
-    fields aggregate the streaming ledger for the whole execution.
-    ``wall_s`` is the end-to-end wall time of the call that produced the
-    report; ``traced`` marks reports whose region detail was captured at
-    trace time (jitted resident path) and republished per call."""
+    ``regions`` maps each fused region's terminal symbol to its
+    :class:`RegionRecord`; the scalar fields aggregate the streaming ledger
+    for the whole execution (deterministic byte arithmetic: ``h2d_bytes``
+    the encoded payload bytes that crossed the host→device link,
+    ``peak_chunk_bytes`` the largest decoded working set a streamed region
+    held on device at once, ``peak_state_bytes`` the largest carried
+    accumulator state).  ``wall_s`` is the wall time of the call that
+    produced the report (dispatch only, for the asynchronous jitted path);
+    ``traced`` marks reports whose region detail was captured at trace time
+    (jitted resident path) and republished per call."""
 
     regions: Dict[str, RegionRecord] = field(default_factory=dict)
     wall_s: float = 0.0
@@ -756,7 +740,7 @@ class ExecutionReport:
     degradation: str = ""  # final rung when degraded ("materialized"|"streamed")
 
     def modes(self) -> Dict[str, str]:
-        """``{terminal symbol: execution mode}`` — the old REGION_MODES view."""
+        """``{terminal symbol: execution mode}``."""
         return {s: r.mode for s, r in self.regions.items()}
 
     def mode(self, sym: str, default: str = "") -> str:
@@ -769,9 +753,7 @@ class ExecutionReport:
     def copy(self) -> "ExecutionReport":
         rep = ExecutionReport(
             regions={
-                s: RegionRecord(
-                    r.sym, r.mode, r.family, r.wall_s, r.chunks, r.h2d_bytes
-                )
+                s: RegionRecord(r.sym, r.mode, r.family, r.chunks, r.h2d_bytes)
                 for s, r in self.regions.items()
             },
         )
@@ -857,11 +839,8 @@ def _record_region(
     family: str = "",
     chunks: int = 0,
     h2d_bytes: int = 0,
-    wall_s: float = 0.0,
 ) -> None:
-    """Write one region's telemetry to the active report AND the legacy
-    ``REGION_MODES`` view (kept for external callers)."""
-    REGION_MODES[sym] = mode
+    """Write one region's telemetry to the active report."""
     if _ACTIVE_REPORTS:
         rep = _ACTIVE_REPORTS[-1]
         rec = rep.regions.get(sym)
@@ -872,7 +851,6 @@ def _record_region(
             rec.family = family
         rec.chunks += chunks
         rec.h2d_bytes += h2d_bytes
-        rec.wall_s += wall_s
 
 
 def _account_stream(
@@ -882,17 +860,7 @@ def _account_stream(
     peak_chunk_bytes: int = 0,
     peak_state_bytes: int = 0,
 ) -> None:
-    """Update the streaming ledger on the active report AND the legacy
-    ``STREAM_STATS`` view."""
-    STREAM_STATS["regions"] += regions
-    STREAM_STATS["chunks"] += chunks
-    STREAM_STATS["h2d_bytes"] += h2d_bytes
-    STREAM_STATS["peak_chunk_bytes"] = max(
-        STREAM_STATS["peak_chunk_bytes"], peak_chunk_bytes
-    )
-    STREAM_STATS["peak_state_bytes"] = max(
-        STREAM_STATS["peak_state_bytes"], peak_state_bytes
-    )
+    """Update the streaming ledger on the active report."""
     if _ACTIVE_REPORTS:
         rep = _ACTIVE_REPORTS[-1]
         rep.streamed_regions += regions
@@ -900,6 +868,11 @@ def _account_stream(
         rep.h2d_bytes += h2d_bytes
         rep.peak_chunk_bytes = max(rep.peak_chunk_bytes, peak_chunk_bytes)
         rep.peak_state_bytes = max(rep.peak_state_bytes, peak_state_bytes)
+
+
+# ---------------------------------------------------------------------------
+# out-of-core streaming (DESIGN.md §10)
+# ---------------------------------------------------------------------------
 
 
 def _terminal_family(term) -> str:
@@ -1292,6 +1265,9 @@ def _publish_region_result(term, out, kind, sorted_on, f, env, refs):
 
 _REGION_CACHE: Dict[tuple, tuple] = {}
 _REGION_CACHE_MAX = 256
+#: traces of every region fn (a Python side effect of their bodies, so it
+#: fires per trace only); ``StreamedExecutable`` counts its calls' share
+_REGION_TRACES = 0
 
 
 def _make_region_fn(rest, f0, builts, src_cols0, sigma, allow_sorted, need):
@@ -1312,6 +1288,8 @@ def _make_region_fn(rest, f0, builts, src_cols0, sigma, allow_sorted, need):
     holder = [None, None]
 
     def run(frame_cols, frame_masks, dict_tables, src_cols, pvals):
+        global _REGION_TRACES
+        _REGION_TRACES += 1
         f = Frame(
             {
                 v: Table(
@@ -1447,15 +1425,18 @@ def _make_streamed_chain_fn(
     holders = [[None, None] for _ in segments]
 
     def run(payloads, dict_tables, src_cols, pvals, state):
+        global _REGION_TRACES
+        _REGION_TRACES += 1
         cols = {}
-        for c, kind, bits, ref, block in colspecs:
-            if kind == "raw":
-                cols[c] = payloads[c]["data"]
-            else:
-                cols[c] = DK.decode_traced(
-                    kind, payloads[c], bits=bits, ref=ref, block=block,
-                    n=n, chunk_rows=chunk_rows,
-                )
+        with jax.named_scope(SCOPE_DECODE):
+            for c, kind, bits, ref, block in colspecs:
+                if kind == "raw":
+                    cols[c] = payloads[c]["data"]
+                else:
+                    cols[c] = DK.decode_traced(
+                        kind, payloads[c], bits=bits, ref=ref, block=block,
+                        n=n, chunk_rows=chunk_rows,
+                    )
         if colspecs and colspecs[0][1] == "raw":
             mask = payloads["__mask__"]["data"]
         else:
@@ -1539,7 +1520,6 @@ def _exec_streamed_chain(ct, segments, env, refs, sigma, allow_sorted, params):
     from repro.core import plan as P
     from repro.data import storage as STG
 
-    t_chain = time.perf_counter()
     seg0, seg_last = segments[0], segments[-1]
     term = seg_last.rest[-1]
     needed = seg0.needed
@@ -1681,7 +1661,6 @@ def _exec_streamed_chain(ct, segments, env, refs, sigma, allow_sorted, params):
         family=_terminal_family(term),
         chunks=nchunks,
         h2d_bytes=chain_h2d,
-        wall_s=time.perf_counter() - t_chain,
     )
 
     # -- publish the terminal -----------------------------------------------
@@ -1736,7 +1715,6 @@ def _stream_kernel_chunks(
     pipe, rest, var, rel = seg.pipe, seg.rest, seg.var, seg.rel
     term = rest[-1]
     nchunks = ct.n_chunks
-    t_kern = time.perf_counter()
     t0 = ct.chunk_device(0, needed, pad=True)
     f0 = Frame({var: t0}, (var,), {var: rel})
     scratch_env, scratch_refs = dict(env), {}
@@ -1777,7 +1755,6 @@ def _stream_kernel_chunks(
         family=_terminal_family(term),
         chunks=nchunks,
         h2d_bytes=kern_h2d,
-        wall_s=time.perf_counter() - t_kern,
     )
     lanes = (
         tuple(a for a, _ in term.values)
@@ -2001,15 +1978,6 @@ def _region_stages(
 KERNEL_SLOTS = 1 << 16  # per-dictionary resident slot bound of the fused
 # kernel (mirrors FusionCostModel.kernel_slots — a bigger slab radix-
 # partitions instead of de-fusing)
-
-# DEPRECATED execution-mode log per fused region (keyed by the region's
-# terminal symbol): "kernel-resident" / "kernel-radix" for the Pallas paths,
-# "xla" / "xla-radix-planned" for the compiled region function.  Written at
-# trace time — the mode is a static property of (region, policy, dict
-# metadata).  Kept populated for external callers; in-repo readers use
-# ``last_report().regions`` (the same modes plus family/wall/chunk detail).
-REGION_MODES: Dict[str, str] = {}
-
 
 def _kernel_pipeline(pipe, rest, f, env, refs, sigma, allow_sorted, params, need):
     """Try the fused Pallas kernel for the (already input-resolved) region;
@@ -2414,8 +2382,8 @@ def _run_shared_region(region, envs, refss, db, sigma, allow_sorted, params_list
 
     Under the Pallas kernel policy each branch dispatches through its own
     ``_run_pipeline`` instead — the fused kernel's per-region residency
-    accounting stays honest and ``REGION_MODES`` reports the path that
-    actually produced each terminal; the scan dedup is an XLA-path win."""
+    accounting stays honest and the report's region modes name the path
+    that actually produced each terminal; the scan dedup is an XLA-path win."""
     from repro.core import plan as P
     from repro.kernels import ops as _kops
 
@@ -2564,7 +2532,7 @@ def execute_shared_plan(
     try:
         return _execute_shared_plan_body(
             sp, db, sigma, allow_sorted, params_list, exchange_impl,
-            repartition_impl, envs, refss, rep,
+            repartition_impl, envs, refss,
         )
     finally:
         _end_report(rep, time.perf_counter() - t_plan)
@@ -2572,7 +2540,7 @@ def execute_shared_plan(
 
 def _execute_shared_plan_body(
     sp, db, sigma, allow_sorted, params_list, exchange_impl,
-    repartition_impl, envs, refss, rep,
+    repartition_impl, envs, refss,
 ):
     from repro.core import plan as P
 
@@ -2606,30 +2574,19 @@ def _execute_shared_plan_body(
                 if ri is not None and not done[ri]:
                     break  # stalled on a pending shared region
                 if ri is None:
-                    t_node = time.perf_counter()
                     _exec_node(
                         nd, envs[i], refss[i], db, sigma, allow_sorted,
                         params_list[i], exchange_impl, repartition_impl,
                     )
-                    if isinstance(nd, P.Pipeline):
-                        rec = rep.regions.get(nd.out)
-                        if rec is not None and rec.wall_s == 0.0:
-                            rec.wall_s = time.perf_counter() - t_node
                 pos[i] += 1
                 progress = True
         if all(pos[i] >= len(p.nodes) for i, p in enumerate(sp.plans)):
             break
         for ri, rg in enumerate(sp.regions):
             if not done[ri] and _ready(rg):
-                t_rg = time.perf_counter()
                 _run_shared_region(
                     rg, envs, refss, db, sigma, allow_sorted, params_list
                 )
-                dt = time.perf_counter() - t_rg
-                for b in rg.branches:
-                    rec = rep.regions.get(b.pipe.stages[-1].out)
-                    if rec is not None and rec.wall_s == 0.0:
-                        rec.wall_s = dt
                 done[ri] = True
                 progress = True
         if not progress:  # pragma: no cover
@@ -3059,7 +3016,9 @@ class StreamedExecutable:
             plan = plan.plan
         self.plan = plan
         self.sigma = sigma
-        self.trace_count = 1  # region fns trace on first use, then cache
+        #: region-fn traces during this executable's calls: the first call
+        #: of a shape, or a chunk whose decode recipe is new; never a rebind
+        self.trace_count = 0
         self.calls = 0
         self.last_report: Optional[ExecutionReport] = None
 
@@ -3068,6 +3027,7 @@ class StreamedExecutable:
 
     def __call__(self, db: Dict[str, "Table"], params=None):
         self.calls += 1
+        traces = _REGION_TRACES
         try:
             out = execute_plan(
                 self.plan, db, sigma=self.sigma,
@@ -3075,6 +3035,8 @@ class StreamedExecutable:
             )
         except Exception as e:  # noqa: BLE001
             _raise_classified(e)
+        finally:
+            self.trace_count += _REGION_TRACES - traces
         rep = last_report()  # eager driver: the report is per call already
         rep.trace_count = self.trace_count
         self.last_report = rep

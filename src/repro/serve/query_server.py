@@ -74,10 +74,11 @@ import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro import errors
-from repro.core.adapt import result_items
+from repro.core.adapt import block_result, result_items
 from repro.exec import engine as E
 from repro.exec.queries import QUERIES, Query
 
@@ -131,7 +132,7 @@ class _Shape:
     plan: object = None  # fused physical plan (shared-scan merge input)
     session_shape: object = None  # repro.session.Shape (ladder entry point)
     served: int = 0
-    busy_s: float = 0.0  # execution wall attributed to this shape
+    busy_s: float = 0.0  # batch wall, dispatch to answers, attributed to this shape
     ewma_s: Optional[float] = None  # warm batch-wall EWMA (deadline predictor)
 
 
@@ -203,6 +204,9 @@ class QueryServer:
             "faults": 0,  # typed faults observed while serving
             "degraded": 0,  # responses produced below the primary rung
             "errors": 0,  # responses carrying a typed error
+            # seconds served requests waited in the queue: the sum of
+            # (their batch's start - submit)
+            "queue_wait_s": 0.0,
         }
         self._lat = {"warm": [], "cold": []}
         self._busy = {"warm": 0.0, "cold": 0.0}
@@ -534,7 +538,8 @@ class QueryServer:
         batch_retries = 0
         while results is None:
             try:
-                shapes, results = self._execute_batch(batch)
+                with jax.profiler.TraceAnnotation(E.SPAN_DISPATCH):
+                    shapes, results = self._execute_batch(batch)
             except Exception as e:  # noqa: BLE001 — typed triage below
                 typed = errors.classified(e)
                 if not isinstance(typed, errors.ReproError):
@@ -553,6 +558,16 @@ class QueryServer:
                 out.extend(self._step_degraded(batch, warm, t0))
                 self.counters["batches"] += 1
                 return out
+        rep = E.last_report()
+        rep.retries += batch_retries
+        self.session._last_report = rep
+        # wait for the device before converting, so a trace tells the two
+        # apart; the clocks below read once the answers are on the host
+        with jax.profiler.TraceAnnotation(E.SPAN_SYNC):
+            for res in results:
+                block_result(res)
+        with jax.profiler.TraceAnnotation(E.SPAN_CONVERT):
+            items = [result_items(res) for res in results]
         done = self._clock()
         self._busy["warm" if warm else "cold"] += done - t0
         uniq = list({id(s): s for s in shapes}.values())
@@ -560,14 +575,13 @@ class QueryServer:
             s.busy_s += (done - t0) / len(uniq)
         if warm:
             self._note_wall(self._shapes[head], done - t0)
-        rep = E.last_report()
-        rep.retries += batch_retries
-        for req, s, res in zip(batch, shapes, results):
+        for req, s, res in zip(batch, shapes, items):
+            self.counters["queue_wait_s"] += t0 - req.t_submit
             resp = QueryResponse(
                 rid=req.rid,
                 qname=req.qname,
                 params=req.params,
-                result=result_items(res),
+                result=res,
                 latency_s=done - req.t_submit,
                 warm=warm,
                 batch_size=len(batch),
@@ -588,22 +602,29 @@ class QueryServer:
         through the degradation ladder so one poisoned request (or a
         mode-wide OOM) cannot strand the others."""
         out = []
+        start = t0
         for req in batch:
             try:
-                shape, res, retries = self._execute_one(req)
+                with jax.profiler.TraceAnnotation(E.SPAN_DISPATCH):
+                    shape, res, retries = self._execute_one(req)
             except errors.ReproError as e:
                 out.append(self._fail(req, e, warm=warm))
                 continue
-            done = self._clock()
             rep = E.last_report()
             rep.retries += retries
             if rep.degraded:
                 self.counters["degraded"] += 1
+            with jax.profiler.TraceAnnotation(E.SPAN_SYNC):
+                block_result(res)
+            with jax.profiler.TraceAnnotation(E.SPAN_CONVERT):
+                items = result_items(res)
+            done = self._clock()
+            self.counters["queue_wait_s"] += start - req.t_submit
             resp = QueryResponse(
                 rid=req.rid,
                 qname=req.qname,
                 params=req.params,
-                result=result_items(res),
+                result=items,
                 latency_s=done - req.t_submit,
                 warm=warm,
                 batch_size=1,

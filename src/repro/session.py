@@ -11,9 +11,9 @@ with the cold half paid once per query *shape* and every later call a warm
 cache hit.  The session owns the pieces the old API made callers wire by
 hand — ``chunk_db`` + the matching ``FusionCostModel(chunk_rows=...)`` for
 out-of-core databases, the mesh + ``Δ_net`` for sharded execution,
-``plan.fuse(streamed=...)``, the executable caches — and replaces the
-``REGION_MODES``/``STREAM_STATS`` globals with ``session.report()``, the
-structured :class:`repro.exec.engine.ExecutionReport` of the last call.
+``plan.fuse(streamed=...)``, the executable caches — and reports each
+call through ``session.report()``, the structured
+:class:`repro.exec.engine.ExecutionReport` of the last one.
 
 With ``adapt=`` truthy the session plans through
 :class:`repro.core.adapt.AdaptivePlanner`: near-cost Alg.-1 candidates are
@@ -434,6 +434,7 @@ class Session:
             rep.faults += faults
             rep.degraded = idx
             rep.degradation = mode if idx else ""
+            self._last_report = rep
             return out
 
     def shape(self, q: Union[str, Query, L.Expr]) -> Shape:
@@ -508,12 +509,12 @@ class Session:
             shape.synth_runs = len(shape.planner.races)
         out = self.execute_shape(shape, bound)
         shape.served += 1
-        self._last_report = E.last_report()
         return result_items(out)
 
     # -- observability -------------------------------------------------------
     def report(self) -> Optional[E.ExecutionReport]:
-        """The structured ExecutionReport of this session's last query."""
+        """The structured ExecutionReport of this session's last query,
+        asked through ``query`` or served by a ``QueryServer``."""
         return self._last_report
 
     def explain(self, q: Union[str, Query, L.Expr]) -> Dict[str, object]:
