@@ -183,10 +183,9 @@ class ShardedDictResult:
         return self.keys, self.vals, self.valid
 
     def items_np(self):
-        import numpy as np
+        from repro.exec import engine as E
 
-        ks, vs, valid = map(np.asarray, (self.keys, self.vals, self.valid))
-        return {int(k): vs[i] for i, k in enumerate(ks) if valid[i]}
+        return E.host_items(self.keys, self.vals, self.valid)
 
     def size(self) -> int:
         import numpy as np

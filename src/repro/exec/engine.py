@@ -33,6 +33,19 @@ from repro.data.table import Table
 from repro.testing import faults as _faults
 
 
+def host_items(keys, vals, valid) -> Dict[int, np.ndarray]:
+    """The ``{key: value row}`` host view of a dictionary's slot arrays.
+
+    One ``jax.device_get`` fetches the three arrays (their copies overlap);
+    the valid slots are compacted with numpy, so Python touches each kept
+    entry once and never an empty slot.  Keys are Python ``int`` in slot
+    order (dictionary keys are int32); each value is a row of the compacted
+    host copy, with the slot array's dtype and row shape."""
+    ks, vs, valid = jax.device_get((keys, vals, valid))
+    m = valid.astype(bool)
+    return dict(zip(ks[m].tolist(), vs[m]))
+
+
 @dataclass
 class DictResult:
     """A materialized LLQL dictionary: backend table + its annotation."""
@@ -41,10 +54,7 @@ class DictResult:
     table: object  # HashTable | SortedTable
 
     def items_np(self) -> Dict[int, np.ndarray]:
-        mod = registry.get(self.ds)
-        ks, vs, valid = mod.items(self.table)
-        ks, vs, valid = np.asarray(ks), np.asarray(vs), np.asarray(valid)
-        return {int(k): vs[i] for i, k in enumerate(ks) if valid[i]}
+        return host_items(*self.arrays())
 
     def arrays(self) -> Tuple[jax.Array, jax.Array, jax.Array]:
         return registry.get(self.ds).items(self.table)
@@ -2724,8 +2734,7 @@ class PlanResult:
         return self.keys, self.vals, self.valid
 
     def items_np(self) -> Dict[int, np.ndarray]:
-        ks, vs, valid = map(np.asarray, (self.keys, self.vals, self.valid))
-        return {int(k): vs[i] for i, k in enumerate(ks) if valid[i]}
+        return host_items(self.keys, self.vals, self.valid)
 
     def size(self) -> int:
         return int(np.asarray(self.valid).sum())

@@ -207,6 +207,10 @@ class QueryServer:
             # seconds served requests waited in the queue: the sum of
             # (their batch's start - submit)
             "queue_wait_s": 0.0,
+            # dictionary slots fetched to the host for answers, and the
+            # entries those answers kept
+            "result_slots": 0,
+            "result_entries": 0,
         }
         self._lat = {"warm": [], "cold": []}
         self._busy = {"warm": 0.0, "cold": 0.0}
@@ -567,7 +571,7 @@ class QueryServer:
             for res in results:
                 block_result(res)
         with jax.profiler.TraceAnnotation(E.SPAN_CONVERT):
-            items = [result_items(res) for res in results]
+            items = [self._convert(res) for res in results]
         done = self._clock()
         self._busy["warm" if warm else "cold"] += done - t0
         uniq = list({id(s): s for s in shapes}.values())
@@ -617,7 +621,7 @@ class QueryServer:
             with jax.profiler.TraceAnnotation(E.SPAN_SYNC):
                 block_result(res)
             with jax.profiler.TraceAnnotation(E.SPAN_CONVERT):
-                items = result_items(res)
+                items = self._convert(res)
             done = self._clock()
             self.counters["queue_wait_s"] += start - req.t_submit
             resp = QueryResponse(
@@ -639,6 +643,16 @@ class QueryServer:
             self._busy["warm" if warm else "cold"] += done - t0
             t0 = done
         return out
+
+    def _convert(self, res):
+        """One result's host answer (``result_items``), counting the slots
+        a dictionary-valued result fetched and the entries it kept."""
+        items = result_items(res)
+        keys = getattr(res, "keys", None)
+        if isinstance(keys, jax.Array):
+            self.counters["result_slots"] += keys.shape[0]
+            self.counters["result_entries"] += len(items)
+        return items
 
     def _note_wall(self, shape: _Shape, wall_s: float) -> None:
         shape.ewma_s = (
