@@ -142,6 +142,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, devices, t_star
     from repro.serve.query_server import QueryServer
 
     traces = _Traces()
+    devices = devices[: cell.chips]  # on a larger host the other chips hold nothing of the cell
     cfg, mix = cell.config, cell.mix
     sf = float(cfg["scale_factor"])
     qmods = {q: spec.query(q) for q in queries_of(mix)}

@@ -3,15 +3,14 @@ raw trace decoder and the attribution on traces recorded on one TPU v5e,
 the per-layer metrics on synthetic windows, and traced runs of every cell
 on the CPU at a small scale."""
 import lzma
-import time
 from pathlib import Path
 
-import jax
 import pytest
 
-from bench import attribute, run, spec, trace_reduce, xplane_meta
+from bench import attribute, spec, trace_reduce, xplane_meta
 from bench.stats import Completion
-from bench.tests.test_bench_run import CELLS, SEED, small
+from bench.tests.cells import CELLS, SHARDED, run_cell
+from bench.tests.test_bench_run import cell_of
 from bench.window import Window
 from repro.exec import engine as E
 
@@ -151,16 +150,17 @@ def test_queue_ms_reads_the_counter():
                         after={"responses": 5, "queue_wait_s": 1.0})) is None
 
 
-@pytest.mark.parametrize("name", CELLS)
-def test_a_traced_run_reports_every_metric_of_its_cell(name, monkeypatch):
+@pytest.mark.parametrize("name", CELLS + [SHARDED])
+def test_a_traced_run_reports_every_metric_of_its_cell(name, request):
     """The result line of ``--trace 1`` (peaks of a v5e, since the CPU has
-    none) holds the server's metrics the cell lists, and its idle gaps
-    name the server's spans."""
-    peaks = spec.peaks
-    monkeypatch.setattr(spec, "peaks", lambda kind: peaks("TPU v5 lite"))
-    res = run.run(small(name), SEED, 1.0, True, jax.devices(), time.perf_counter())
+    none), on as many devices as the cell asks for, holds the server's
+    metrics the cell lists, and its idle gaps name the server's spans."""
+    cell = cell_of(name, request)
+    res, counters = run_cell(cell, trace=True)
     assert res["correct"], res["checks"]
-    new = [m["name"] for m in spec.cell(name).per_layer if m["name"] in SERVER]
+    assert counters["degraded"] == counters["retries"] == counters["errors"] == 0, counters
+    assert res["device"]["count"] == cell.chips and res["device"]["busy_s"] > 0
+    new = [m["name"] for m in cell.per_layer if m["name"] in SERVER]
     assert new
     for m in new:
         assert res["metrics"][m]["value"] > 0, (m, res["metrics"])

@@ -190,19 +190,53 @@ def test_references_match_the_programs_oracles():
         assert kd == 0 and rel < 1e-5, qname
 
 
-def test_every_cell_resolves_by_name():
-    for w in BENCH["workloads"]:
-        cell = spec.cell(w["name"])
+def _check_cells(bench: dict) -> None:
+    """Every cell of ``bench`` resolves by name and has the shape the
+    benchmark's contract asks: one chip or four, as its configuration
+    says; a configuration of several chips shards over all of them and
+    streams nothing (``Session`` refuses both together); at most half of
+    the cells, rounded down, on four chips, though one always may."""
+    workloads = bench["workloads"]
+    for w in workloads:
+        cell = spec.cell(w["name"], bench)
         assert cell.config["scale_factor"] > 0
         assert {m["name"] for m in cell.end_to_end} >= {"setup_s"}
         assert cell.per_layer, w["name"]
         for q in queries_of(cell.mix):
             assert f"{q}.rel_err" in cell.config["limits"]
-    for m in BENCH["per_layer"]:
+        assert w["chips"] in (1, 4) and cell.chips == cell.config["chips"] == w["chips"], w["name"]
+    for m in bench["per_layer"]:
         assert callable(spec.metric_reader(m["name"]))
-    for c in BENCH["configs"]:
-        assert spec.config(c["name"])["name"] == c["name"]
+    for c in bench["configs"]:
+        cfg = spec.config(c["name"])
+        assert cfg["name"] == c["name"]
         assert c["file"] == f"bench/configs/{c['name']}.json"
+        if cfg["chips"] > 1:
+            session = cfg.get("session", {})
+            assert session.get("shards") == cfg["chips"], c["name"]
+            assert "memory_budget" not in session, c["name"]
+    four = sum(w["chips"] == 4 for w in workloads)
+    assert four <= max(1, len(workloads) // 2), four
+
+
+def test_every_cell_resolves_by_name():
+    _check_cells(BENCH)
+
+
+def test_a_four_chip_cell_resolves_by_name(sharded_bench):
+    _check_cells(sharded_bench)
+    cfg_file = spec.BENCH / "configs" / "tpch-sf1-shard4.json"
+    cfg = json.loads(cfg_file.read_text())
+    # sharded and streamed at once: Session would refuse it
+    cfg_file.write_text(json.dumps(dict(cfg, session={"shards": 4, "memory_budget": 10**8})))
+    with pytest.raises(AssertionError, match="tpch-sf1-shard4"):
+        _check_cells(sharded_bench)
+    cfg_file.write_text(json.dumps(cfg))
+    # one four-chip cell may stand alone, a second beside it is one too many
+    four = sharded_bench["workloads"][-1]
+    _check_cells(dict(sharded_bench, workloads=[four]))
+    with pytest.raises(AssertionError):
+        _check_cells(dict(sharded_bench, workloads=[four, dict(four, name="tpch-sf1-shard4.again")]))
 
 
 def test_new_files_are_found_by_name(tmp_path, monkeypatch):

@@ -98,3 +98,24 @@ def test_breakdown(red):
 def test_transfers_to_the_device_are_found(red):
     # each request's binding goes to the device as scalars
     assert red.h2d_s() > 0 and len(red.transfers) > 0
+
+
+def test_a_window_over_four_chips():
+    """Busy time and device ops are means over the chips, an empty chip
+    counting as idle; idle gaps are read on the first chip."""
+    Op = T.Op
+    ops = {
+        0: [Op(0, 40_000, "a", 40_000)],
+        1: [Op(0, 40_000, "a", 40_000), Op(50_000, 70_000, "b", 20_000)],
+        2: [Op(10_000, 90_000, "c", 80_000)],
+    }
+    client = [(0, 100_000, "bench.window"), (0, 100_000, "bench.step")]
+    red = T.Reduction((0, 100_000), ops, client, [], n_devices=4)
+    assert red.window_s == pytest.approx(1e-4)
+    assert red.busy_s == pytest.approx((40_000 + 60_000 + 80_000 + 0) / 4 / 1e9)
+    bd = red.breakdown()
+    assert dict((n, s) for n, s in bd["device_ops"]) == pytest.approx(
+        {"a": 80_000 / 4 / 1e9, "c": 80_000 / 4 / 1e9, "b": 20_000 / 4 / 1e9}
+    )
+    assert red.gaps() == [(40_000, 100_000)]
+    assert bd["idle_gaps"] == [["bench.step", pytest.approx(60_000 / 1e9)]]
